@@ -104,7 +104,9 @@ class KeystreamEngine:
         self.key = jnp.asarray(key, jnp.uint32)
         self.mesh = mesh
         self.axis = axis
-        self.interpret = interpret   # only 'sharded' consults it (None=auto)
+        # only 'sharded' consults it: compiled unless interpret=True is
+        # passed explicitly (CPU tests on a host mesh)
+        self.interpret = bool(interpret)
         self.caps = type(self).query_caps(mesh=mesh, axis=axis)
         if variant == "auto":
             variant = self.caps.preferred_variant
@@ -383,7 +385,7 @@ class JaxEngine(KeystreamEngine):
 
 
 class _PallasBase(KeystreamEngine):
-    _interpret: Optional[bool] = None   # None = kernel-side auto
+    _interpret: bool     # fixed per registered engine, never inferred
 
     def _run(self, rc, noise, mats):
         if noise is not None and not self.params.n_noise:

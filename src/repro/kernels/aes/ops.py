@@ -10,17 +10,11 @@ import jax.numpy as jnp
 from repro.kernels.aes.aes import BLK, aes_ctr_pallas
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def aes_ctr_kernel_apply(round_keys, nonce12, counters,
-                         interpret: bool | None = None):
+def aes_ctr_kernel_apply(round_keys, nonce12, counters, *,
+                         interpret: bool):
     """round_keys: (11,16) u8/u32; nonce12: (12,) u8/u32; counters: (lanes,)
     u32.  Returns (lanes, 16) uint8 keystream blocks."""
-    if interpret is None:
-        interpret = _auto_interpret()
     rk = jnp.asarray(round_keys, jnp.uint32)[..., None]      # (11,16,1)
     nonce = jnp.asarray(nonce12, jnp.uint32)[:, None]        # (12,1)
     counters = jnp.asarray(counters, jnp.uint32)

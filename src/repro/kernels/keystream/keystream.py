@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import redplan as RP
 from repro.core import schedule as S
@@ -129,14 +130,14 @@ def _keystream_kernel(params: CipherParams, sched: Schedule, plan,
                 # dense per-lane matrix plane, delivered storage-permuted
                 # (`mat_storage_perm`): stored-state in -> stored-state out,
                 # so there is no flip handling here at all
+                # (per-branch ref slices: the whole plane is never loaded)
                 ma, _ = op.mat_slice
-                mats = mats_ref[...]
                 lazy_d = p_i.has(RP.LAZY_DENSE)
                 x = jnp.concatenate([
                     mrmc_dense_apply(
                         mod,
-                        mats[ma + i * t * t : ma + (i + 1) * t * t].reshape(
-                            t, t, -1),
+                        mats_ref[ma + i * t * t : ma + (i + 1) * t * t, :]
+                        .reshape(t, t, -1),
                         x[i * t : (i + 1) * t],
                         x_bound=p_i.in_bound if lazy_d else None,
                         lazy=lazy_d,
@@ -279,12 +280,31 @@ def keystream_pallas(params: CipherParams, key_n1, rc_cl, noise_ll=None, *,
 
     kernel = functools.partial(_keystream_kernel, p, schedule, plan,
                                with_noise, with_mats)
+    out_block = (p.l, BLK)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((p.l, BLK), lambda i: (0, i)),
+        out_specs=pl.BlockSpec(out_block, lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((p.l, padded), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(
+            [s.block_shape for s in in_specs] + [out_block])),
         interpret=interpret,
     )(*args)
     return out[:, :lanes] if pad else out
+
+
+#: scoped VMEM the kernel body's own temporaries may take beyond its
+#: pipeline buffers (v5e's default scoped limit)
+_VMEM_HEADROOM = 16 << 20
+#: v5e has 128 MiB of VMEM; leave room for the compiler's internal scratch
+_VMEM_CAP = 100 << 20
+
+
+def _vmem_limit(block_shapes) -> int:
+    """Scoped-VMEM limit for one keystream call: every block (32-bit words)
+    double-buffered by the Pallas pipeline, plus headroom.  PASTA-128L's
+    (32768, BLK) matrix plane alone is 16 MiB per buffer, over the
+    default limit."""
+    buffered = sum(2 * 4 * int(np.prod(s)) for s in block_shapes)
+    return min(buffered + _VMEM_HEADROOM, _VMEM_CAP)

@@ -21,9 +21,9 @@ from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.params import CipherParams
 from repro.core.redplan import DEFAULT_REDUCTION
 from repro.core.schedule import build_schedule
@@ -33,16 +33,11 @@ if TYPE_CHECKING:  # annotation only — core.engine imports this module
     from repro.core.cipher import Cipher
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("params", "interpret", "variant",
                                              "reduction"))
-def keystream_kernel_apply(params: CipherParams, key, rc, noise=None,
-                           interpret: bool | None = None,
-                           variant: str = "normal", mats=None,
-                           reduction: str = DEFAULT_REDUCTION):
+def keystream_kernel_apply(params: CipherParams, key, rc, noise=None, *,
+                           interpret: bool, variant: str = "normal",
+                           mats=None, reduction: str = DEFAULT_REDUCTION):
     """key: (n,) u32; rc: (lanes, n_round_constants) u32; noise: (lanes, l)
     int32 or None; mats: (lanes, n_matrix_constants) u32 or None (dense
     matrix planes for stream-sourced MRMC schedules).  Returns (lanes, l)
@@ -55,8 +50,6 @@ def keystream_kernel_apply(params: CipherParams, key, rc, noise=None,
     plan is rebuilt (cached) inside the trace.  Ragged lane counts are
     padded/trimmed inside :func:`keystream_pallas`.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
     sched = build_schedule(params, variant)
     rc_p = rc.T                                       # (n_consts, lanes)
     noise_p = None
@@ -74,9 +67,8 @@ def keystream_kernel_apply(params: CipherParams, key, rc, noise=None,
 
 def keystream_kernel_sharded(params: CipherParams, key, rc, noise=None, *,
                              mesh=None, axis: str = "data",
-                             interpret: bool | None = None,
-                             variant: str = "normal", mats=None,
-                             reduction: str = DEFAULT_REDUCTION):
+                             interpret: bool, variant: str = "normal",
+                             mats=None, reduction: str = DEFAULT_REDUCTION):
     """Lane-sharded fused consumer: rc/noise/mats split over ``mesh[axis]``.
 
     Same signature/semantics as :func:`keystream_kernel_apply`; lanes are
@@ -120,17 +112,14 @@ def keystream_kernel_sharded(params: CipherParams, key, rc, noise=None, *,
     return out[:lanes]
 
 
-def presto_keystream(cipher: Cipher, block_ctrs, interpret: bool | None = None):
+def presto_keystream(cipher: Cipher, block_ctrs, *, interpret: bool):
     """Full accelerator pipeline: XOF producer -> fused kernel consumer.
 
     Backend selection is engine-routed: ``interpret`` picks between the
-    registered "pallas" and "pallas-interpret" engines (None = whatever the
-    current backend supports; see :func:`repro.core.engine.resolve_engine`).
+    registered "pallas" and "pallas-interpret" engines.
     """
     from repro.core.engine import make_engine  # runtime: engine imports us
 
-    if interpret is None:
-        interpret = _auto_interpret()
     eng = make_engine("pallas-interpret" if interpret else "pallas",
                       cipher.params, cipher.key)
     consts = cipher.round_constant_stream(block_ctrs)

@@ -133,10 +133,11 @@ def mrmc_dense_apply(mod: Modulus, m_ttl, x_tl,
     x_tl:  (t, lanes) uint32 state, entries < q.  Returns (t, lanes).
 
     Accumulation mirrors `Modulus.matvec_dense` (the lane-minor sibling):
-    products < q sum raw in uint32 in `Modulus.dense_chunk_schedule`
-    chunks (a reshape, one fused sum per level) with one reduce per
-    chunk, then one raw fold of the reduced partials — the ONE shared
-    overflow policy `Modulus.dense_accumulate_sites` proves safe.
+    products < q sum raw (uint32 bits, see `_sum_u32`) in
+    `Modulus.dense_chunk_schedule` chunks (a reshape, one fused sum per
+    level) with one reduce per chunk, then one raw fold of the reduced
+    partials — the ONE shared overflow policy
+    `Modulus.dense_accumulate_sites` proves safe.
     ``lazy=True`` is the reduction plan's lazy-dense policy: each
     product's final reduce is deferred (raw values < 3q) and the chunk
     width shrinks to match; ``x_bound`` relaxes the state-operand
@@ -152,12 +153,24 @@ def mrmc_dense_apply(mod: Modulus, m_ttl, x_tl,
         pb = mod.q
     ch, nch = mod.dense_chunk_schedule(t, pb)
     lanes = prods.shape[-1]
-    s = jnp.sum(prods.reshape(t, nch, ch, lanes), axis=2,
-                dtype=jnp.uint32)                     # (t, nch, lanes)
+    s = _sum_u32(prods.reshape(t, nch, ch, lanes), axis=2)  # (t, nch, lanes)
     s = mod.reduce(s, ch * pb)                        # each < q
     if nch == 1:
         return s[:, 0]
-    return mod.reduce(jnp.sum(s, axis=1, dtype=jnp.uint32), nch * mod.q)
+    return mod.reduce(_sum_u32(s, axis=1), nch * mod.q)
+
+
+def _sum_u32(x, axis: int):
+    """uint32 sum along ``axis`` computed on the int32 view.
+
+    Mosaic (the TPU kernel compiler) implements no unsigned reductions.
+    Two's-complement addition wraps exactly like uint32 addition, so the
+    bits equal the uint32 sum; the overflow proof
+    (`Modulus.dense_accumulate_sites`) keeps that sum below 2^32 anyway.
+    """
+    s = jnp.sum(jax.lax.bitcast_convert_type(x, jnp.int32), axis=axis,
+                dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(s, jnp.uint32)
 
 
 def _mrmc_kernel(mat: np.ndarray, q: int, x_ref, o_ref):

@@ -12,12 +12,8 @@ from repro.core.params import CipherParams
 from repro.kernels.mrmc.mrmc import BLK, mrmc_pallas
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("params", "interpret"))
-def mrmc_kernel_apply(params: CipherParams, x, interpret: bool | None = None):
+def mrmc_kernel_apply(params: CipherParams, x, *, interpret: bool):
     """x: (lanes, n) uint32 row-major states -> (lanes, n) MRMC output.
 
     Branch-aware: a multi-branch state (PASTA, n = branches·v²) applies the
@@ -25,8 +21,6 @@ def mrmc_kernel_apply(params: CipherParams, x, interpret: bool | None = None):
     (lanes, b, v, v) becomes a (v, v, lanes·b) lane-major block and the
     kernel is oblivious to where lanes end and branches begin.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
     lanes, n = x.shape
     v, b = params.v, params.branches
     assert n == params.n

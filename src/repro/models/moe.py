@@ -20,8 +20,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
 

@@ -55,6 +55,8 @@ import argparse
 import asyncio
 import base64
 import concurrent.futures
+import os
+import pathlib
 import struct
 import time
 from typing import Dict, Optional, Tuple
@@ -456,6 +458,12 @@ class ServeClient:
     server-side auto-rotations.
     """
 
+    #: most blocks per oracle keystream call: bounds a bulk request's XOF
+    #: draw (pasta-128l draws 33312 words per block, so 4096 blocks in one
+    #: call would take GiBs) while keeping calls few, since the eager
+    #: oracle's cost per call barely depends on its size
+    ORACLE_CHUNK = 1024
+
     def __init__(self, host: str, port: int, tenant: str,
                  codec: Optional[int] = None):
         self.host, self.port, self.tenant = host, port, tenant
@@ -471,10 +479,12 @@ class ServeClient:
         self._reader_task: Optional[asyncio.Task] = None
         self._write_lock: Optional[asyncio.Lock] = None
         self._ciphers: Dict[bytes, object] = {}
+        self._producer = None
 
     # ------------------------------------------------------------------
     async def connect(self) -> dict:
         from repro.core.params import get_params
+        from repro.core.producer import make_producer
 
         self.reader, self.writer = await asyncio.open_connection(
             self.host, self.port)
@@ -487,6 +497,7 @@ class ServeClient:
         self.hello = hello
         self.params = get_params(hello["cipher"])
         self.key = np.asarray(hello["key"], np.uint32)
+        self._producer = make_producer(None, self.params)
         return hello
 
     async def close(self) -> None:
@@ -558,16 +569,35 @@ class ServeClient:
         return r["stats"]
 
     def _cipher(self, nonce: np.ndarray):
-        """Per-nonce single-stream Cipher (the ref-engine oracle) — cached
-        so pipelined submits on one session reuse the producer binding."""
+        """Per-nonce single-stream Cipher (the ref-engine oracle), cached.
+        All of them share one producer, so a new nonce (a rotation) does
+        not trace and compile the producer again."""
         from repro.core.cipher import Cipher
 
         key = np.asarray(nonce, np.uint8).tobytes()
         ci = self._ciphers.get(key)
         if ci is None:
-            ci = Cipher(self.params, self.key, nonce)
+            ci = Cipher(self.params, self.key, nonce,
+                        producer=self._producer)
             self._ciphers[key] = ci
         return ci
+
+    def _keystream(self, nonce: np.ndarray, ctrs: np.ndarray):
+        """Oracle keystream for ``ctrs`` under ``nonce``.  Every call has
+        one size, the block count rounded up to a power of two and capped
+        at :attr:`ORACLE_CHUNK`, so the oracle compiles for few shapes
+        however requests are sized; padding lanes repeat the last counter
+        and are trimmed."""
+        import jax.numpy as jnp
+
+        n = len(ctrs)
+        ci = self._cipher(nonce)
+        step = min(self.ORACLE_CHUNK, 1 << (n - 1).bit_length())
+        padded = np.concatenate([ctrs, np.full((-n) % step, ctrs[-1],
+                                               ctrs.dtype)])
+        parts = [ci.keystream(jnp.asarray(padded[i:i + step]))
+                 for i in range(0, len(padded), step)]
+        return jnp.concatenate(parts)[:n]
 
     def session_remaining(self, session: int) -> int:
         from repro.core import cipher as _c
@@ -592,7 +622,7 @@ class ServeClient:
         st = self.sessions[session]
         ctrs = st["next_ctr"] + np.arange(blocks, dtype=np.uint32)
         st["next_ctr"] += blocks
-        z = self._cipher(st["nonce"]).keystream(jnp.asarray(ctrs))
+        z = self._keystream(st["nonce"], ctrs)
         ct = np.asarray(self.params.mod.add(jnp.asarray(tokens), z))
         r = await self.call({
             "op": "submit", "tenant": self.tenant, "session": session,
@@ -621,7 +651,7 @@ class ServeClient:
             return r, None
         nonce = np.asarray(r["nonce"], np.uint8)
         ctrs = np.asarray(r["ctrs"], np.uint32)
-        z = self._cipher(nonce).keystream(jnp.asarray(ctrs))
+        z = self._keystream(nonce, ctrs)
         back = np.asarray(self.params.mod.sub(
             jnp.asarray(np.asarray(r["result"], np.uint32)), z))
         # re-sync the mirror from the echo (auto-rotation resets it)
@@ -634,7 +664,29 @@ class ServeClient:
 # ==========================================================================
 # CLI
 # ==========================================================================
-def main(argv=None) -> int:
+#: fixed home of JAX's persistent compilation cache inside the checkout
+#: (the directory is part of each entry's key, so it must not move)
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, so
+    nothing is set here; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Entry points call this before compiling;
+    importing this module configures nothing.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+def build_parser() -> argparse.ArgumentParser:
     from repro.core.params import REGISTRY
 
     ap = argparse.ArgumentParser(
@@ -655,13 +707,23 @@ def main(argv=None) -> int:
     ap.add_argument("--overload", choices=["reject", "shed"],
                     default="reject")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
 
-    registry = TenantRegistry(
+
+def make_registry(args: argparse.Namespace, *,
+                  warmup: bool = False) -> TenantRegistry:
+    """The tenant registry the CLI's parsed ``args`` describe."""
+    return TenantRegistry(
         args.cipher, capacity=args.capacity, window=args.window,
         engine=args.engine, deadline_s=args.deadline_ms / 1e3,
         max_pending_lanes=args.max_pending_lanes, overload=args.overload,
-        seed=args.seed)
+        seed=args.seed, warmup=warmup)
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    registry = make_registry(args)
 
     async def run():
         plane = ServePlane(registry, host=args.host, port=args.port)

@@ -20,9 +20,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 def _q8(x):

@@ -64,10 +64,13 @@ def test_engine_matrix_bit_exact(engine, name, with_noise, variant):
 
 
 def test_sharded_engine_matches_ref_on_host_mesh():
-    """'sharded' needs a mesh; on a 1-wide axis it must equal the oracle."""
+    """'sharded' needs a mesh; on a 1-wide axis it must equal the oracle.
+    It compiles its kernel unless interpret mode is asked for explicitly,
+    as a CPU host must."""
     ci = make_cipher("hera-128a", seed=17)
     mesh = jax.make_mesh((1,), ("data",))
-    eng = make_engine("sharded", ci.params, ci.key, mesh=mesh)
+    assert not make_engine("sharded", ci.params, ci.key, mesh=mesh).interpret
+    eng = make_engine("sharded", ci.params, ci.key, mesh=mesh, interpret=True)
     rc = ci.round_constant_stream(jnp.arange(LANES, dtype=jnp.uint32))["rc"]
     np.testing.assert_array_equal(
         np.array(eng.keystream_from_constants(rc)),
