@@ -44,6 +44,7 @@ from typing import Any, Iterable, Iterator, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cipher import CipherBatch, decode_fixed, encode_fixed
 from repro.core.engine import EngineSpec
@@ -224,22 +225,26 @@ class KeystreamFarm:
         """Dispatch the (async) producer for one window — the pool's
         pluggable `ConstantsProducer` (memoizing backends short-circuit
         repeated windows here).  ``plane`` narrows the payload ("vector"
-        when the matrix FIFO produces matrices separately)."""
-        return self.batch.producer.produce(
-            self.batch.xof_tables(), plan.session_ids, plan.block_ctrs, plane
-        )
+        when the matrix FIFO produces matrices separately).  Traced as
+        the span ``farm.produce``."""
+        with TraceAnnotation("farm.produce", plane=plane):
+            return self.batch.producer.produce(
+                self.batch.xof_tables(), plan.session_ids, plan.block_ctrs,
+                plane)
 
     def produce_matrix(self, plan: WindowPlan):
         """Dispatch matrix-plane-only production for one window (the
         prefetch-ahead FIFO's producer half)."""
-        return self.batch.producer.produce(
-            self.batch.xof_tables(), plan.session_ids, plan.block_ctrs,
-            "matrix"
-        )
+        with TraceAnnotation("farm.produce", plane="matrix"):
+            return self.batch.producer.produce(
+                self.batch.xof_tables(), plan.session_ids, plan.block_ctrs,
+                "matrix")
 
     def consume(self, constants):
-        """Run the engine consumer on produced constants."""
-        return self.engine(constants)
+        """Run the engine consumer on produced constants (the span
+        ``farm.consume``)."""
+        with TraceAnnotation("farm.consume"):
+            return self.engine(constants)
 
     # ------------------------------------------------------------------
     def pipeline(self) -> "FarmPipeline":

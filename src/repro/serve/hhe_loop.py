@@ -36,11 +36,22 @@ raising.
 
 Latency accounting: a request completes when the window holding its last
 lane is materialized; `latency_stats` reports p50/p99 over completed
-requests, the numbers `benchmarks/serve_load_bench.py` replays against.
+requests.
+
+Tracing: each scheduler step is marked for ``jax.profiler`` (``hhe.submit``,
+``hhe.fire``, ``hhe.carve``, ``hhe.materialize`` with its
+``hhe.device_wait``/``hhe.fetch``/``hhe.scatter`` steps, and
+``hhe.respond``), tagged with the request's ``seq`` or the window's serial
+``window``.  They land in a profiler trace on the device trace's clock and
+cost about a microsecond each when no profiler is running.  A step that
+does host work of bounded size is one ``TraceAnnotation`` span; a step
+that can wait on the device is two instant marks (:func:`marked`), since
+one span would last as long as the wait.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -50,6 +61,7 @@ from typing import Deque, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cipher import (
     CipherBatch,
@@ -63,6 +75,22 @@ OPS = ("keystream", "encrypt", "decrypt", "encrypt_tokens", "decrypt_tokens")
 
 #: admission-control policies when the pending-lane bound is hit
 OVERLOAD_POLICIES = ("reject", "shed")
+
+
+@contextlib.contextmanager
+def marked(name: str, **stats):
+    """Trace a step that can wait on the device as two instant profiler
+    marks: ``name``, carrying ``stats``, where it starts, and
+    ``name + ".end"`` where it ends.  A span would last through the wait,
+    tens of milliseconds, and a trace analysis that matches device gaps
+    to host events scans as far back as the longest host event."""
+    with TraceAnnotation(name, **stats):
+        pass
+    try:
+        yield
+    finally:
+        with TraceAnnotation(name + ".end"):
+            pass
 
 
 class HHEServerSaturated(RuntimeError):
@@ -256,7 +284,8 @@ class HHEServer:
     def submit_entry(self, req: HHERequest) -> Optional[_Entry]:
         """submit(), but returns the internal entry (the async front end
         correlates responses by ``entry.seq``)."""
-        with self._lock:
+        with self._lock, marked("hhe.submit", seq=self._seq,
+                                blocks=req.blocks, op=req.op):
             if not 0 <= req.session_id < len(self.batch.sessions):
                 raise KeyError(
                     f"unknown session {req.session_id} (pool has "
@@ -290,8 +319,8 @@ class HHEServer:
                 # old-nonce lanes must materialize before the table row is
                 # replaced — rotation is a materialization boundary; the
                 # forced responses surface via flush()/pop_completed()
-                self._fire_full()
-                self._fire_partial()
+                self._fire_full("rotate")
+                self._fire_partial("rotate")
                 self._drain()
                 sess = self.batch.rotate_session(req.session_id)
             ctrs = sess.take_window(req.blocks)
@@ -342,23 +371,32 @@ class HHEServer:
         for p, z in self._pipe.push(plan):
             self._materialize(p, z)
 
-    def _fire_full(self) -> int:
+    def _fire(self, cause: str, count: int) -> None:
+        """Carve ``count`` buffered lanes into a window and push it; the
+        window's serial is the count of windows pushed before it."""
+        with marked("hhe.fire", cause=cause, valid=count,
+                    window=self.windows_served + len(self._pending_windows)):
+            with TraceAnnotation("hhe.carve", lanes=count):
+                plan = self._carve(count)
+            self._push(plan)
+
+    def _fire_full(self, cause: str = "fill") -> int:
         """Dispatch every FULL buffered window (the fill event)."""
         fired = 0
         while self._buffered >= self.window:
-            self._push(self._carve(self.window))
+            self._fire(cause, self.window)
             self.fill_fires += 1
             fired += 1
         return fired
 
-    def _fire_partial(self) -> bool:
+    def _fire_partial(self, cause: str) -> bool:
         """Dispatch the part-full tail window, padded (deadline/flush/
         rotation edges).  No-ops when nothing is buffered — the empty-
         window dispatch the old pull loop could make is structurally
         impossible here."""
         if not self._buffered:
             return False
-        self._push(self._carve(self._buffered))
+        self._fire(cause, self._buffered)
         return True
 
     def _drain(self) -> None:
@@ -366,17 +404,28 @@ class HHEServer:
             self._materialize(p, z)
 
     def _materialize(self, plan: WindowPlan, z) -> None:
-        z = np.asarray(jax.block_until_ready(z))
-        t_now = time.perf_counter()
-        self._pending_windows.popleft()
-        self._inflight -= plan.valid
-        self.windows_served += 1
-        for j in range(plan.valid):
-            entry, row = plan.meta[j]
-            entry.rows[row] = z[j]
-            entry.remaining -= 1
-            if entry.remaining == 0:
-                self._completed.append(self._respond(entry, t_now))
+        with marked("hhe.materialize", window=self.windows_served,
+                    valid=plan.valid):
+            with marked("hhe.device_wait"):
+                jax.block_until_ready(z)
+            with TraceAnnotation("hhe.fetch"):
+                z = np.asarray(z)
+            t_now = time.perf_counter()
+            self._pending_windows.popleft()
+            self._inflight -= plan.valid
+            self.windows_served += 1
+            done = []
+            with TraceAnnotation("hhe.scatter", lanes=plan.valid):
+                for j in range(plan.valid):
+                    entry, row = plan.meta[j]
+                    entry.rows[row] = z[j]
+                    entry.remaining -= 1
+                    if entry.remaining == 0:
+                        done.append(entry)
+            for entry in done:
+                with marked("hhe.respond", seq=entry.seq, op=entry.req.op,
+                            blocks=entry.req.blocks):
+                    self._completed.append(self._respond(entry, t_now))
 
     def _respond(self, entry: _Entry, t_done: float) -> HHEResponse:
         req, z = entry.req, jnp.asarray(entry.rows)
@@ -432,7 +481,7 @@ class HHEServer:
                 t = self._oldest_pending_t()
                 now = time.perf_counter() if now is None else now
                 if t is not None and now - t >= self.deadline_s:
-                    self._fire_partial()
+                    self._fire_partial("deadline")
                     self._drain()
                     self.deadline_fires += 1
             return self.pop_completed()
@@ -453,8 +502,8 @@ class HHEServer:
         don't own response delivery."""
         with self._lock:
             if self._buffered:
-                self._fire_full()
-                self._fire_partial()
+                self._fire_full("flush")
+                self._fire_partial("flush")
             self._drain()
 
     def pop_completed(self) -> List[HHEResponse]:

@@ -62,8 +62,9 @@ import time
 from typing import Dict, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.serve.hhe_loop import HHERequest, HHEServerSaturated
+from repro.serve.hhe_loop import HHERequest, HHEServerSaturated, marked
 from repro.serve.tenants import TenantRegistry
 
 try:
@@ -152,7 +153,18 @@ async def read_frame(reader: asyncio.StreamReader) -> Tuple[dict, int]:
     if length > MAX_FRAME:
         raise ValueError(f"frame of {length} bytes exceeds {MAX_FRAME}")
     body = await reader.readexactly(length)
-    return decode_body(body, codec), codec
+    with TraceAnnotation("plane.decode", bytes=length):
+        return decode_body(body, codec), codec
+
+
+async def _write_reply(writer: asyncio.StreamWriter, lock: asyncio.Lock,
+                       reply: dict, codec: int) -> None:
+    with TraceAnnotation("plane.encode") as span:
+        frame = encode_frame(reply, codec)
+        span.set_metadata(bytes=len(frame))
+    async with lock:
+        writer.write(frame)
+        await writer.drain()
 
 
 # ==========================================================================
@@ -185,8 +197,6 @@ class ServePlane:
         self._waiters: Dict[tuple, asyncio.Future] = {}
         # responses that materialized before their waiter registered
         self._unclaimed: Dict[tuple, object] = {}
-        self.connections = 0
-        self.frames = 0
 
     # ------------------------------------------------------------------
     async def start(self) -> Tuple[str, int]:
@@ -219,10 +229,19 @@ class ServePlane:
         async with self._server:
             await self._server.serve_forever()
 
-    async def _farm(self, fn, *args):
-        """Run farm-touching work on the plane's single worker thread."""
+    async def _farm(self, kind: str, fn, *args):
+        """Run farm-touching work on the plane's single worker thread,
+        marked as the step ``plane.job`` (``kind`` names the op;
+        ``queued_us`` is the wait from hand-off to the worker's start)."""
+        t_handoff = time.perf_counter_ns()
+
+        def job():
+            queued_us = (time.perf_counter_ns() - t_handoff) // 1000
+            with marked("plane.job", kind=kind, queued_us=queued_us):
+                return fn(*args)
+
         return await asyncio.get_running_loop().run_in_executor(
-            self._exec, fn, *args)
+            self._exec, job)
 
     # ------------------------------------------------------------------
     # waiter plumbing (every method here runs on the event-loop thread)
@@ -231,13 +250,14 @@ class ServePlane:
         """Resolve futures for responses a worker call just collected;
         park responses whose waiter isn't registered yet."""
         base = (tenant.tenant_id, tenant.generation)
-        for resp in responses:
-            key = (*base, resp.seq)
-            fut = self._waiters.pop(key, None)
-            if fut is None:
-                self._unclaimed[key] = resp
-            elif not fut.done():
-                fut.set_result(resp)
+        with TraceAnnotation("plane.resolve", responses=len(responses)):
+            for resp in responses:
+                key = (*base, resp.seq)
+                fut = self._waiters.pop(key, None)
+                if fut is None:
+                    self._unclaimed[key] = resp
+                elif not fut.done():
+                    fut.set_result(resp)
 
     def _register_waiter(self, tenant, seq: int) -> asyncio.Future:
         key = (tenant.tenant_id, tenant.generation, seq)
@@ -271,13 +291,12 @@ class ServePlane:
 
         while True:
             await asyncio.sleep(self.tick_s)
-            for tenant, done in await self._farm(one_pass):
+            for tenant, done in await self._farm("tick", one_pass):
                 self._resolve(tenant, done)
 
     # ------------------------------------------------------------------
     async def _handle_conn(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter) -> None:
-        self.connections += 1
         write_lock = asyncio.Lock()
         pending = set()
         try:
@@ -286,7 +305,6 @@ class ServePlane:
                     msg, codec = await read_frame(reader)
                 except (asyncio.IncompleteReadError, ConnectionResetError):
                     break
-                self.frames += 1
                 if msg.get("op") == "submit":
                     # submits pipeline: spawn a task so later frames on
                     # this connection are parsed while windows fill.  The
@@ -300,9 +318,7 @@ class ServePlane:
                     continue
                 reply = await self._dispatch(msg)
                 reply["id"] = msg.get("id")
-                async with write_lock:
-                    writer.write(encode_frame(reply, codec))
-                    await writer.drain()
+                await _write_reply(writer, write_lock, reply, codec)
         finally:
             for task in pending:
                 task.cancel()
@@ -336,7 +352,8 @@ class ServePlane:
             return {"ok": False,
                     "error": f"this plane serves {self.registry.cipher!r}, "
                              f"not {cipher!r}"}
-        tenant = await self._farm(self.registry.get, str(msg["tenant"]))
+        tenant = await self._farm("hello", self.registry.get,
+                                  str(msg["tenant"]))
         p = self.registry.params
         return {
             "ok": True, "tenant": tenant.tenant_id,
@@ -349,7 +366,7 @@ class ServePlane:
 
     async def _op_open_session(self, msg: dict) -> dict:
         sess = await self._farm(
-            self.registry.open_session, str(msg["tenant"]))
+            "open_session", self.registry.open_session, str(msg["tenant"]))
         return {"ok": True, "session": sess.index,
                 "nonce": sess.nonce, "generation": sess.generation}
 
@@ -362,7 +379,7 @@ class ServePlane:
             # the quiesce inside rotate_session may have completed submits
             return tenant, sess, tenant.server.pop_completed()
 
-        tenant, sess, done = await self._farm(blocking)
+        tenant, sess, done = await self._farm("rotate", blocking)
         self._resolve(tenant, done)
         return {"ok": True, "session": sess.index,
                 "nonce": sess.nonce, "generation": sess.generation}
@@ -370,10 +387,10 @@ class ServePlane:
     async def _op_stats(self, msg: dict) -> dict:
         tid = msg.get("tenant")
         if tid is None:
-            stats = await self._farm(self.registry.stats)
+            stats = await self._farm("stats", self.registry.stats)
             return {"ok": True, "stats": stats}
         tenant = self.registry.peek(str(tid))
-        stats = await self._farm(tenant.server.latency_stats)
+        stats = await self._farm("stats", tenant.server.latency_stats)
         return {"ok": True, "stats": stats}
 
     # ---- submit (future-resolved) --------------------------------------
@@ -383,9 +400,7 @@ class ServePlane:
         reply = await self._op_submit(msg)
         reply["id"] = msg.get("id")
         try:
-            async with write_lock:
-                writer.write(encode_frame(reply, codec))
-                await writer.drain()
+            await _write_reply(writer, write_lock, reply, codec)
         except (ConnectionResetError, BrokenPipeError):
             pass
 
@@ -416,7 +431,7 @@ class ServePlane:
                 return tenant, "shed", None, done
             return tenant, "entry", entry, done
 
-        tenant, kind, value, done = await self._farm(blocking)
+        tenant, kind, value, done = await self._farm("submit", blocking)
         if kind == "saturated":
             self._resolve(tenant, done)
             return {"ok": False, "error": "saturated", "detail": value}
@@ -707,6 +722,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--overload", choices=["reject", "shed"],
                     default="reject")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profiler-port", type=int, default=None,
+                    help="serve jax.profiler captures on this port (off "
+                         "by default), for the plane.*/hhe.*/farm.* steps")
     return ap
 
 
@@ -723,6 +741,10 @@ def make_registry(args: argparse.Namespace, *,
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     enable_compile_cache()
+    if args.profiler_port is not None:
+        import jax
+
+        jax.profiler.start_server(args.profiler_port)
     registry = make_registry(args)
 
     async def run():

@@ -86,3 +86,18 @@ def test_sharded_engine_compiles_without_collectives(topo):
         rc, noise).compile().as_text()
     assert "tpu_custom_call" in text
     assert not [c for c in COLLECTIVES if c in text]
+
+
+def test_keystream_kernel_keeps_the_name_the_trace_readers_match(topo):
+    """The chip benchmark's kernel_us_per_block and
+    keystream_kernel_roofline sum the device ops whose name contains
+    ``keystream_kernel``: the Pallas call is the HLO instruction named
+    after ``keystream_kernel_apply``, which the device trace shows."""
+    p = get_params("hera-80")
+    one = SingleDeviceSharding(topo.devices[0])
+    text = keystream_kernel_apply.lower(
+        p, _u32((p.n,), one), _u32((BLK, p.n_round_constants), one),
+        interpret=False).compile().as_text()
+    calls = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and all("keystream_kernel" in c for c in calls), calls
