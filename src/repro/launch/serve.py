@@ -164,7 +164,7 @@ class EncryptedChannel:
         return {"count": 0, "p50_ms": 0.0, "p99_ms": 0.0, "mean_ms": 0.0,
                 "queue_depth_lanes": 0, "inflight_lanes": 0,
                 "windows_served": 0, "fill_fires": 0, "deadline_fires": 0,
-                "shed": 0, "rejected": 0}
+                "shed": 0, "rejected": 0, "overlapped_reads": 0}
 
 
 def main(argv=None):

@@ -34,6 +34,19 @@ to the farm).  Queue-depth, shed/reject, and fire-cause counters ride in
 a server that served zero windows reports zeroed percentiles instead of
 raising.
 
+Read-back: a window's keystream is read back only once more work is
+queued on the device behind it.  Each window the pipeline hands back
+starts its device-to-host copy at once and waits in a ready queue; the
+oldest is materialized only while that queue holds more than
+``depth - 1`` windows.  At the default depth 2, window k-1 is read after
+producer k+1 and consumer k are dispatched, so the chip runs producer k+1
+through the worker's host steps (fetch, scatter, respond, the next carve
+and dispatch).  Every drain edge (deadline, flush, rotation, quiesce)
+dispatches what is left in the pipeline and then reads every ready
+window in order; depth 1 reads each window as soon as it is pushed.
+``_respond``'s modular arithmetic runs on the host CPU device, so a
+deferred read never queues small device ops behind a producer run.
+
 Latency accounting: a request completes when the window holding its last
 lane is materialized; `latency_stats` reports p50/p99 over completed
 requests.
@@ -42,17 +55,22 @@ Tracing: each scheduler step is marked for ``jax.profiler`` (``hhe.submit``,
 ``hhe.fire``, ``hhe.carve``, ``hhe.materialize`` with its
 ``hhe.device_wait``/``hhe.fetch``/``hhe.scatter`` steps, and
 ``hhe.respond``), tagged with the request's ``seq`` or the window's serial
-``window``.  They land in a profiler trace on the device trace's clock and
-cost about a microsecond each when no profiler is running.  A step that
-does host work of bounded size is one ``TraceAnnotation`` span; a step
-that can wait on the device is two instant marks (:func:`marked`), since
-one span would last as long as the wait.
+``window``; ``hhe.materialize`` also carries ``behind``, the windows whose
+producer was dispatched after this window's consumer: the device work
+queued behind the read (``overlapped_reads`` in `latency_stats` counts
+the reads with ``behind >= 1``).  They land in a profiler trace on the
+device trace's clock and cost about a microsecond each when no profiler
+is running.  A step that does host work of bounded size is one
+``TraceAnnotation`` span; a step that can wait on the device is two
+instant marks (:func:`marked`), since one span would last as long as the
+wait.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from collections import deque
@@ -75,6 +93,30 @@ OPS = ("keystream", "encrypt", "decrypt", "encrypt_tokens", "decrypt_tokens")
 
 #: admission-control policies when the pending-lane bound is hit
 OVERLOAD_POLICIES = ("reject", "shed")
+
+
+@functools.lru_cache(maxsize=None)
+def _host_device():
+    """The host CPU device ``_respond`` computes on, or None (JAX's default
+    device) where JAX runs without its CPU backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
+@functools.partial(jax.jit, static_argnames=("mod", "op"))
+def _finish(mod, op: str, z, payload, delta):
+    """A completed request's result from its keystream ``z``: one program
+    per op and shape.  ``delta`` is traced, so its division stays one."""
+    if op == "encrypt":
+        return mod.add(encode_fixed(mod, payload, delta), z)
+    if op == "encrypt_tokens":                  # exact Z_q, no encoding
+        return mod.add(payload, z)
+    mq = mod.sub(payload, z)
+    if op == "decrypt_tokens":
+        return mq.astype(jnp.int32)
+    return decode_fixed(mod, mq, delta)         # decrypt
 
 
 @contextlib.contextmanager
@@ -163,7 +205,11 @@ class HHEServer:
     ``engine`` picks the farm's consumer backend (any registered
     `repro.core.engine` name or instance); ``consumer``/``interpret`` are
     the legacy spellings; ``depth`` sets the farm's producer→consumer FIFO
-    depth.  ``plan`` applies a measured :class:`repro.core.tuner.
+    depth, and with it the read-back rule: a window is read back only while
+    more than ``depth - 1`` consumed windows wait to be read, so at depth 2
+    the next window's producer is queued on the device during every read
+    outside a drain, and depth 1 reads each window as it is pushed.
+    ``plan`` applies a measured :class:`repro.core.tuner.
     StreamPlan` in one shot — producer, engine, variant, depth, and (when
     ``window`` is not given) window size.  With ``auto_rotate`` (default),
     a session whose counter space cannot fit an incoming request is
@@ -231,6 +277,10 @@ class HHEServer:
         self._buffered = 0                # lanes in _frags
         self._inflight = 0                # valid lanes dispatched, unmaterialized
         self._pending_windows: Deque[WindowPlan] = deque()
+        # consumed windows not yet read back: (plan, keystream, windows
+        # pushed when its consumer was dispatched); they stay in
+        # _pending_windows and _inflight until _materialize
+        self._ready: Deque[tuple] = deque()
         self._completed: List[HHEResponse] = []
         self._seq = 0
         self.latencies: List[float] = []
@@ -239,6 +289,7 @@ class HHEServer:
         self.deadline_fires = 0
         self.shed_count = 0
         self.rejected_count = 0
+        self.overlapped_reads = 0
         # submit may run on the event-loop thread while service/flush run
         # in an executor (serve/server.py) — one reentrant lock serializes
         # every scheduler mutation
@@ -368,14 +419,25 @@ class HHEServer:
     def _push(self, plan: WindowPlan) -> None:
         self._inflight += plan.valid
         self._pending_windows.append(plan)
-        for p, z in self._pipe.push(plan):
-            self._materialize(p, z)
+        self._queue_reads(self._pipe.push(plan))
+        while len(self._ready) > self.farm.depth - 1:
+            self._materialize(*self._ready.popleft())
+
+    def _queue_reads(self, consumed) -> None:
+        """Start each consumed window's copy to the host and queue its read."""
+        for plan, z in consumed:
+            z.copy_to_host_async()
+            self._ready.append((plan, z, self._pushed()))
+
+    def _pushed(self) -> int:
+        """Windows pushed to the pipeline so far."""
+        return self.windows_served + len(self._pending_windows)
 
     def _fire(self, cause: str, count: int) -> None:
         """Carve ``count`` buffered lanes into a window and push it; the
         window's serial is the count of windows pushed before it."""
         with marked("hhe.fire", cause=cause, valid=count,
-                    window=self.windows_served + len(self._pending_windows)):
+                    window=self._pushed()):
             with TraceAnnotation("hhe.carve", lanes=count):
                 plan = self._carve(count)
             self._push(plan)
@@ -400,12 +462,15 @@ class HHEServer:
         return True
 
     def _drain(self) -> None:
-        for p, z in self._pipe.drain():
-            self._materialize(p, z)
+        self._queue_reads(self._pipe.drain())
+        while self._ready:
+            self._materialize(*self._ready.popleft())
 
-    def _materialize(self, plan: WindowPlan, z) -> None:
+    def _materialize(self, plan: WindowPlan, z, pushed: int) -> None:
+        behind = self._pushed() - pushed
+        self.overlapped_reads += behind > 0
         with marked("hhe.materialize", window=self.windows_served,
-                    valid=plan.valid):
+                    valid=plan.valid, behind=behind):
             with marked("hhe.device_wait"):
                 jax.block_until_ready(z)
             with TraceAnnotation("hhe.fetch"):
@@ -428,23 +493,17 @@ class HHEServer:
                     self._completed.append(self._respond(entry, t_now))
 
     def _respond(self, entry: _Entry, t_done: float) -> HHEResponse:
-        req, z = entry.req, jnp.asarray(entry.rows)
-        mod = self.batch.params.mod
+        req = entry.req
         if req.op == "keystream":
             result = entry.rows
-        elif req.op == "encrypt":
-            result = np.asarray(mod.add(
-                encode_fixed(mod, req.payload, req.delta), z))
-        elif req.op == "encrypt_tokens":        # exact Z_q, no encoding
-            result = np.asarray(mod.add(
-                jnp.asarray(req.payload, jnp.uint32), z))
-        elif req.op == "decrypt_tokens":
-            result = np.asarray(mod.sub(
-                jnp.asarray(req.payload, jnp.uint32), z
-            ).astype(jnp.int32))
-        else:  # decrypt
-            mq = mod.sub(jnp.asarray(req.payload, jnp.uint32), z)
-            result = np.asarray(decode_fixed(mod, mq, req.delta))
+        else:
+            dtype = np.float32 if req.op == "encrypt" else np.uint32
+            # on the host: a device op here would queue behind the
+            # producer run dispatched ahead of this read
+            with jax.default_device(_host_device()):
+                result = np.asarray(_finish(
+                    self.batch.params.mod, req.op, entry.rows,
+                    np.asarray(req.payload, dtype), req.delta))
         lat = t_done - entry.t_submit
         self.latencies.append(lat)
         return HHEResponse(request=req, result=result,
@@ -538,5 +597,6 @@ class HHEServer:
                 deadline_fires=self.deadline_fires,
                 shed=self.shed_count,
                 rejected=self.rejected_count,
+                overlapped_reads=self.overlapped_reads,
             )
             return stats

@@ -15,11 +15,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.cipher import Cipher, CipherBatch
+from repro.core.cipher import Cipher, CipherBatch, decode_fixed, encode_fixed
 from repro.serve.hhe_loop import (
+    OPS,
     HHERequest,
     HHEServer,
     HHEServerSaturated,
+    _Entry,
 )
 from repro.serve.tenants import TenantRegistry, derive_tenant_key
 
@@ -73,10 +75,166 @@ def test_flush_short_circuits_when_idle():
         "count": 0, "p50_ms": 0.0, "p99_ms": 0.0, "mean_ms": 0.0,
         "queue_depth_lanes": 0, "inflight_lanes": 0, "windows_served": 0,
         "fill_fires": 0, "deadline_fires": 0, "shed": 0, "rejected": 0,
+        "overlapped_reads": 0,
     }
     assert srv.flush() == []
     assert srv.windows_served == 0          # no empty-window dispatch
     assert not srv.busy()
+
+
+# ---------------------------------------------------------------------------
+# Read-back order: a window is read only once later work is queued
+# ---------------------------------------------------------------------------
+def _mixed_run(depth: int):
+    """All five ops with ragged sizes, an auto-rotation and a deadline
+    fire through one server at ``depth``.  Returns the responses by seq,
+    the order of producer dispatches, consumer dispatches and reads (each
+    with the window's serial; reads also say whether a drain made them),
+    and the server."""
+    import repro.core.cipher as cipher_mod
+
+    cb = CipherBatch("hera-80", seed=41)
+    srv = HHEServer(cb, window=4, engine="jax", depth=depth,
+                    deadline_s=10.0)
+    s0, s1 = srv.open_session(), srv.open_session()
+    plans, events, draining = [], [], [False]
+    produce, consume = srv.farm.produce, srv.farm.consume
+    materialize, drain = srv._materialize, srv._drain
+
+    def serial(plan):
+        return next(i for i, p in enumerate(plans) if p is plan)
+
+    def rec_produce(plan, *args):
+        plans.append(plan)
+        events.append(("produce", serial(plan)))
+        return produce(plan, *args)
+
+    def rec_consume(consts):
+        events.append(("consume", sum(e[0] == "consume" for e in events)))
+        return consume(consts)
+
+    def rec_materialize(plan, *args):
+        events.append(("read", serial(plan), draining[0]))
+        return materialize(plan, *args)
+
+    def rec_drain():
+        draining[0] = True
+        try:
+            drain()
+        finally:
+            draining[0] = False
+
+    rng = np.random.default_rng(42)
+    q, l = cb.params.mod.q, cb.params.l
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cipher_mod, "SESSION_CTR_LIMIT", 24)
+        mp.setattr(srv.farm, "produce", rec_produce)
+        mp.setattr(srv.farm, "consume", rec_consume)
+        mp.setattr(srv, "_materialize", rec_materialize)
+        mp.setattr(srv, "_drain", rec_drain)
+        # s0 takes 11 of its 24 counters, so its 14-block request rotates
+        for sess, op, blocks in [
+                (s0, "keystream", 3), (s1, "encrypt", 5), (s0, "decrypt", 2),
+                (s1, "encrypt_tokens", 7), (s0, "decrypt_tokens", 6),
+                (s1, "keystream", 9), (s0, "encrypt_tokens", 14),
+                (s0, "decrypt", 3)]:
+            if op == "keystream":
+                payload = None
+            elif op == "encrypt":
+                payload = rng.uniform(-8, 8, (blocks, l)).astype(np.float32)
+            else:
+                payload = rng.integers(0, q, (blocks, l), dtype=np.uint32)
+            srv.submit(HHERequest(session_id=sess.index, op=op,
+                                  payload=payload, blocks=blocks))
+        assert cb.sessions[s0.index].generation == 1
+        responses = srv.service(now=time.perf_counter() + 60.0)
+    assert srv.pending_lanes() == 0 and not srv._ready
+    assert srv.latency_stats()["deadline_fires"] == 1
+    assert len(responses) == 8
+    return {r.seq: r for r in responses}, events, srv
+
+
+@pytest.fixture(scope="module")
+def serialized_run():
+    return _mixed_run(1)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_windows_are_read_behind_later_work(depth, serialized_run):
+    """Depth 1 produces, consumes and reads each window in turn.  At a
+    deeper FIFO every read outside a drain starts after a later window's
+    producer was dispatched behind the read window's consumer, so the
+    chip has work queued through the read; the served bytes stay those
+    of the serialized run, for every op."""
+    responses, events, srv = _mixed_run(depth)
+    n = srv.windows_served
+    assert sorted(e[1] for e in events if e[0] == "read") == list(range(n))
+    if depth == 1:
+        assert events == [e for k in range(n) for e in
+                          (("produce", k), ("consume", k), ("read", k, False))]
+        assert srv.overlapped_reads == 0
+    else:
+        at = {e[:2]: i for i, e in enumerate(events)}
+        outside = [e[1] for e in events if e[0] == "read" and not e[2]]
+        assert outside
+        for k in outside:
+            assert any(at[("consume", k)] < at[("produce", j)]
+                       < at[("read", k)] for j in range(k + 1, n)), k
+        assert srv.overlapped_reads >= len(outside)
+    want, _, _ = serialized_run
+    assert responses.keys() == want.keys()
+    for seq, r in responses.items():
+        assert r.result.dtype == want[seq].result.dtype
+        np.testing.assert_array_equal(r.result, want[seq].result)
+        np.testing.assert_array_equal(r.block_ctrs, want[seq].block_ctrs)
+
+
+@pytest.mark.parametrize("name, delta", [("hera-80", 1024.0),
+                                         ("rubato-128s", 1000.0)])
+@pytest.mark.parametrize("op", OPS)
+def test_respond_is_bit_exact_with_the_device_path(name, delta, op):
+    """``_respond`` computes on the host and returns NumPy arrays equal,
+    bit for bit and in dtype, to the eager device ops it replaced, edge
+    values 0 and q-1 included, for a scale that is no power of two."""
+    cb = CipherBatch(name, seed=51)
+    srv = HHEServer(cb, window=4, engine="jax")
+    mod, l = cb.params.mod, cb.params.l
+    q = mod.q
+    rng = np.random.default_rng(52)
+    rows = rng.integers(0, q, (6, l), dtype=np.uint32)
+    rows[0, :2] = [0, q - 1]
+    rows[1, :2] = [q - 1, 0]
+    payload = None
+    if op == "encrypt":
+        top = (q // 2 - 1) / delta          # the largest encodable value
+        payload = rng.uniform(-top, top, (6, l)).astype(np.float32)
+        payload[0, :3] = [0.0, top, -top]
+    elif op != "keystream":
+        payload = rng.integers(0, q, (6, l), dtype=np.uint32)
+        payload[0, :2] = [0, q - 1]
+        payload[1, :2] = [0, q - 1]
+    req = HHERequest(session_id=0, op=op, payload=payload, blocks=6,
+                     delta=delta)
+    entry = _Entry(seq=0, req=req, ctrs=np.arange(6), t_submit=0.0,
+                   rows=rows, remaining=0)
+    got = srv._respond(entry, 1.0).result
+
+    z = jnp.asarray(rows)
+    if op == "keystream":
+        want = rows
+    elif op == "encrypt":
+        want = mod.add(encode_fixed(mod, payload, delta), z)
+    elif op == "encrypt_tokens":
+        want = mod.add(jnp.asarray(payload, jnp.uint32), z)
+    elif op == "decrypt_tokens":
+        want = mod.sub(jnp.asarray(payload, jnp.uint32), z).astype(jnp.int32)
+    else:
+        want = decode_fixed(mod, mod.sub(jnp.asarray(payload, jnp.uint32), z),
+                            delta)
+    want = np.asarray(want)
+    assert type(got) is np.ndarray
+    assert got.dtype == want.dtype and got.shape == (6, l)
+    np.testing.assert_array_equal(got, want)
 
 
 # ---------------------------------------------------------------------------

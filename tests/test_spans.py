@@ -44,6 +44,17 @@ PER_WINDOW = ("hhe.fire", "hhe.carve", "farm.produce", "farm.consume",
 MARKED = ("plane.job", "hhe.submit", "hhe.fire", "hhe.materialize",
           "hhe.device_wait", "hhe.respond")
 JOB_KINDS = {"hello", "open_session", "submit", "tick", "rotate", "stats"}
+#: the steps a step runs inside, as PERF.md section 3 lists them
+PARENTS = {"hhe.submit": ("plane.job",),
+           "hhe.fire": ("hhe.submit", "plane.job"),
+           "hhe.carve": ("hhe.fire",),
+           "farm.produce": ("hhe.fire",),
+           "farm.consume": ("hhe.fire", "plane.job"),
+           "hhe.materialize": ("hhe.fire", "plane.job"),
+           "hhe.device_wait": ("hhe.materialize",),
+           "hhe.fetch": ("hhe.materialize",),
+           "hhe.scatter": ("hhe.materialize",),
+           "hhe.respond": ("hhe.materialize",)}
 
 
 @dataclasses.dataclass
@@ -174,6 +185,32 @@ def test_spans_per_window_do_not_grow_with_the_window(tmp_path, window):
     assert sum(counts.values()) == 3 * len(PER_WINDOW) + 2
 
 
+@pytest.mark.parametrize("depth, overlapped", [(1, 0), (2, 3)])
+def test_overlapped_reads_count_the_reads_with_work_behind_them(
+        tmp_path, depth, overlapped):
+    """``overlapped_reads`` counts the reads whose ``hhe.materialize`` mark
+    has ``behind >= 1``.  17 blocks in windows of 4 at depth 2: the three
+    windows read inside the submit and at the flush's push have the next
+    producer behind them, the last two, read in the drain, none; depth 1
+    reads every window before the next is dispatched."""
+    cb = CipherBatch("hera-80", seed=8)
+    srv = HHEServer(cb, window=4, engine="jax", depth=depth)
+    s = srv.open_session()
+    srv.warmup()
+
+    def serve():
+        srv.submit(HHERequest(session_id=s.index, blocks=17))
+        return srv.flush()
+
+    spans, (resp,) = traced(tmp_path, serve)
+    reads = named(spans, "hhe.materialize")
+    assert [m.stats["window"] for m in reads] == list(range(5))
+    behind = [m.stats["behind"] for m in reads]
+    assert srv.latency_stats()["overlapped_reads"] \
+        == sum(b >= 1 for b in behind) == overlapped
+    assert behind == ([0] * 5 if depth == 1 else [1, 1, 1, 0, 0])
+
+
 # ---------------------------------------------------------------------------
 # Over the wire: one tenant, two requests and a rotation, through ServePlane
 # ---------------------------------------------------------------------------
@@ -216,6 +253,16 @@ def test_every_span_of_the_serving_path_appears(wire):
     assert all(s.stats["queued_us"] >= 0 for s in named(spans, "plane.job"))
     assert all(s.stats["bytes"] > 0 for s in named(spans, "plane.decode"))
     assert all(s.stats["bytes"] > 0 for s in named(spans, "plane.encode"))
+    assert stats["overlapped_reads"] == sum(
+        s.stats["behind"] >= 1 for s in named(spans, "hhe.materialize"))
+
+
+def test_steps_nest_as_listed(wire):
+    spans, _ = wire
+    for name, parents in PARENTS.items():
+        outer = [s for s in spans if s.name in parents]
+        for step in named(spans, name):
+            assert any(step.inside(p) for p in outer), (name, step)
 
 
 def test_wire_spans_count_windows_and_responses(wire):
