@@ -29,6 +29,11 @@ DRAIN_S = 60.0
 #: closed-loop requests whose replies are checked, drawn from the seed
 CHECK_SAMPLE = 16
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the parts of a run timed on stderr's ``phases:`` line: the drive (set-up,
+#: window and drain), reading the trace, the metric readers, and the
+#: breakdown (``top_ops`` and ``idle_gaps``); a run without a trace reads
+#: 0 for the two trace phases
+PHASES = ("drive_s", "trace_read_s", "readers_s", "breakdown_s")
 
 
 class CompileCounter:
@@ -423,10 +428,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
     peaks = load_peaks(dev.device_kind) if dev.platform == "tpu" else {}
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace \
         else None
+    phases = dict.fromkeys(PHASES, 0.0)
     try:
+        t = time.perf_counter()
         drive = asyncio.run(_drive(cell, seed, seconds, t_start=t_start,
                                    trace_dir=trace_dir,
                                    after_setup=after_setup))
+        phases["drive_s"] = time.perf_counter() - t
         counts = compile_counter().counts
         memory = dev.memory_stats() or {}
         peak_bytes = int(memory.get("peak_bytes_in_use", 0))
@@ -435,7 +443,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
             path = tracemod.find_xplane(trace_dir)
             if keep_trace:
                 shutil.copyfile(path, keep_trace)
+            t = time.perf_counter()
             tr = tracemod.Trace.from_file(path)
+            phases["trace_read_s"] = time.perf_counter() - t
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -465,10 +475,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
                   trace_window_s=drive.trace_window_s,
                   drain_limit=drive.drain_limit)
     metrics = {}
+    t = time.perf_counter()
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = load_reader(m["name"], cell.dirs)(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    phases["readers_s"] = time.perf_counter() - t
     if trace:
         # A declared per-layer metric that finds nothing to read (an event
         # name no longer in the trace, say) fails the run, never vanishes.
@@ -488,8 +500,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False, *,
         busy = [tracemod.busy_s(ln) for ln in ops]
         device["busy_s"] = float(np.mean(busy)) if busy else 0.0
         device["window_s"] = drive.trace_window_s
+        t = time.perf_counter()
         result["breakdown"] = {"device_ops": tracemod.top_ops(ops),
                                "idle_gaps": tracemod.idle_gaps(tr)}
+        phases["breakdown_s"] = time.perf_counter() - t
+    _log("phases: " + " ".join(f"{k}={v:.3f}" for k, v in phases.items()))
     for name, c in checks.items():
         _log(f"check {name}: {c['value']} (must be {c['is']} "
              f"{c['limit']})")

@@ -153,42 +153,70 @@ def top_ops(lines: list, k: int = 10) -> list:
     return [[name, ns * 1e-9] for name, ns in best]
 
 
+#: how much longer than a gap a host event may be and still be charged it
+REACH_NS = 1_000_000
+#: (gap, host event) pairs compared at once, to bound the memory used
+PAIRS_PER_CHUNK = 1 << 22
+
+
 def idle_gaps(trace: Trace, k: int = 10) -> list:
     """[host activity, seconds] of device idle time, by what the host was
     doing: each gap between device ops is charged to the host event
     (shorter than the gap's span plus a millisecond, so that whole-thread
-    umbrella events do not swallow every gap) that overlaps it most."""
+    umbrella events do not swallow every gap) that overlaps it most, the
+    earliest-starting of those that overlap it equally.
+
+    An event that may be charged the gap ``[a, b)`` lasts at most
+    ``(b - a) + REACH_NS``, so only those starting at or after
+    ``a - (b - a) - REACH_NS`` can overlap it: each gap is compared with
+    those alone, and the cost follows the trace, not its longest event."""
     ops = trace.device_ops()
     if not ops:
         return []
-    iv = union(ops[0].start_ns, ops[0].dur_ns)
-    gaps = [(iv[i][1], iv[i + 1][0]) for i in range(len(iv) - 1)
-            if iv[i + 1][0] > iv[i][1]]
-    if not gaps:
+    iv = np.asarray(union(ops[0].start_ns, ops[0].dur_ns),
+                    np.int64).reshape(-1, 2)
+    ga, gb = iv[:-1, 1], iv[1:, 0]
+    keep = gb > ga
+    ga, gb = ga[keep], gb[keep]
+    if not len(ga):
         return []
-    hs, he, hn = [], [], []
+    hs, hd, hn = [], [], []
     for ln in trace.host_lines():
         hs.append(ln.start_ns)
-        he.append(ln.start_ns + ln.dur_ns)
+        hd.append(ln.dur_ns)
         hn.extend(ln.names)
     hs = np.concatenate(hs) if hs else np.zeros(0, np.int64)
-    he = np.concatenate(he) if he else np.zeros(0, np.int64)
+    hd = np.concatenate(hd) if hd else np.zeros(0, np.int64)
     order = np.argsort(hs, kind="stable")
-    hs, he = hs[order], he[order]
-    hn = [hn[i] for i in order.tolist()]
-    longest = int((he - hs).max()) if len(hs) else 0
+    hs, hd = hs[order], hd[order]
+    he = hs + hd
+    hn = [hn[i] for i in order.tolist()] + ["no host event"]
+    reach = (gb - ga) + REACH_NS
+    lo = np.searchsorted(hs, ga - reach, side="left")
+    n = np.searchsorted(hs, gb, side="left") - lo
+    charged = np.full(len(ga), -1, np.int64)    # -1: "no host event"
+    ends = np.cumsum(n)
+    g0 = 0
+    while g0 < len(ga):
+        # the pairs of gaps [g0, g1), gap by gap, events in start order
+        done = int(ends[g0 - 1]) if g0 else 0
+        g1 = max(g0 + 1, int(np.searchsorted(ends, done + PAIRS_PER_CHUNK,
+                                             side="right")))
+        first = ends[g0:g1] - n[g0:g1] - done     # each gap's first pair
+        g = np.repeat(np.arange(g0, g1), n[g0:g1])
+        j = lo[g] + np.arange(len(g)) - first[g - g0]
+        ov = np.minimum(gb[g], he[j]) - np.maximum(ga[g], hs[j])
+        ov[hd[j] > reach[g]] = 0
+        most = np.zeros(g1 - g0, np.int64)
+        has = n[g0:g1] > 0
+        if has.any():
+            most[has] = np.maximum.reduceat(ov, first[has])
+        hit = (ov > 0) & (ov == most[g - g0])
+        gaps_hit, at = np.unique(g[hit], return_index=True)
+        charged[gaps_hit] = j[hit][at]
+        g0 = g1
     tot = defaultdict(int)
-    for a, b in gaps:
-        lo = int(np.searchsorted(hs, a - longest, side="left"))
-        hi = int(np.searchsorted(hs, b, side="left"))
-        best, best_ov = "no host event", 0
-        for j in range(lo, hi):
-            s, e = int(hs[j]), int(he[j])
-            if e - s > (b - a) + 1_000_000:
-                continue
-            ov = min(b, e) - max(a, s)
-            if ov > best_ov:
-                best, best_ov = hn[j], ov
-        tot[best] += b - a
+    for h, d in zip(charged.tolist(), (gb - ga).tolist()):
+        tot[hn[h]] += d
     top = sorted(tot.items(), key=lambda kv: -kv[1])[:k]
     return [[name, ns * 1e-9] for name, ns in top]
